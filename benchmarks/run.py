@@ -29,6 +29,7 @@ from benchmarks import (bench_faults, bench_figure2, bench_figure3,
                         bench_spec_decode, bench_table4, bench_table5,
                         bench_table8, bench_table9, roofline)
 from benchmarks.common import RESULTS
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "overlap": bench_overlap.run,
@@ -123,6 +124,7 @@ def main(argv=None) -> None:
     if args.list:
         print("\n".join(SUITES))
         return
+    enable_compile_cache()
     names = list(dict.fromkeys(args.suites + args.only)) or list(SUITES)
     unknown = [n for n in names if n not in SUITES]
     if unknown:

@@ -15,11 +15,13 @@ if "xla_allow_excess_precision" not in _flags:
 import numpy as np  # noqa: E402
 
 from repro import Session  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_config, get_smoke_config, list_archs  # noqa: E402
 from repro.core import CLI3, InferenceSetting, build_graph  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-9b",
                     choices=list_archs(include_paper=True))

@@ -29,6 +29,7 @@ if "xla_allow_excess_precision" not in _flags:
         (_flags + " --xla_allow_excess_precision=false").strip()
 
 from repro import Session                               # noqa: E402
+from repro.compile_cache import enable_compile_cache    # noqa: E402
 from repro.configs import get_smoke_config, list_archs  # noqa: E402
 from repro.core import CLI2, InferenceSetting, build_graph  # noqa: E402
 from repro.gateway.sse import iter_events               # noqa: E402
@@ -70,6 +71,7 @@ async def stream_chat(host, port, body, tag):
 
 
 async def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b",
                     choices=list_archs(include_paper=True))

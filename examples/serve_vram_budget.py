@@ -24,6 +24,7 @@ if "xla_allow_excess_precision" not in _flags:
         (_flags + " --xla_allow_excess_precision=false").strip()
 
 from repro import Session  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_smoke_config  # noqa: E402
 from repro.core import (CLI2, InferenceSetting, build_graph,  # noqa: E402
                         run_install)
@@ -36,6 +37,7 @@ def make_requests(cfg, batch, prompt_len, new_tokens, seed=1):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen30b-a3b")
     ap.add_argument("--batch", type=int, default=4)

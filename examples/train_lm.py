@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.data import DataPipeline
 from repro.launch.steps import make_train_step
@@ -30,6 +31,7 @@ def preset_cfg(name):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="smoke", choices=["smoke", "100m"])
     ap.add_argument("--steps", type=int, default=60)

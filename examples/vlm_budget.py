@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import Session
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import CLI1, InferenceSetting, run_install
 from repro.core.vlmopt import (RESOLUTIONS, VisionConfig, init_vision_params,
@@ -20,6 +21,7 @@ from repro.core.vlmopt import (RESOLUTIONS, VisionConfig, init_vision_params,
 
 
 def main():
+    enable_compile_cache()
     # runnable: small encoder, flash vs reference numerics
     vc_small = VisionConfig(d=64, layers=2, heads=4)
     params = init_vision_params(jax.random.PRNGKey(0), vc_small, jnp.float32)

@@ -18,4 +18,5 @@ from repro.core.specdec import SpecDecoder  # noqa: F401
 from repro.core.profile_db import ProfileDB  # noqa: F401
 from repro.core.sublayer import STREAMABLE_KINDS  # noqa: F401
 from repro.core.system import (  # noqa: F401
-    CLI1, CLI2, CLI3, SYSTEMS, TPU_V5E, InferenceSetting, SystemConfig)
+    CLI1, CLI2, CLI3, SYSTEMS, TPU_V5E, InferenceSetting, SystemConfig,
+    system_for_device_kind)

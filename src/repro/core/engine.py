@@ -56,6 +56,22 @@ def _blocks_divide(dim: int, block: int) -> bool:
 class SubLayerEngine:
     """Compiled sub-layer step functions shared across layers/chunks/steps."""
 
+    # steps whose KV arguments are donated on accelerators: the stacked
+    # (L,B,KV,S,hd) caches of the attention steps (DESIGN.md §7, §10), the
+    # slot-threaded prefill (§12), the paged steps' page pools (§12) and
+    # the pool-level fold / rollback steps (§12, §14). A caller must never
+    # read an argument it passed at these positions again.
+    DONATED_ARGS = {
+        "attn_step": (2, 3),
+        "attn_prefill_step": (2, 3),
+        "attn_decode_step": (2, 3),
+        "attn_prefill_slot_step": (2, 3),
+        "attn_decode_paged_step": (2, 3),
+        "attn_prefill_paged_step": (2, 3),
+        "fold_page_step": (0, 1),
+        "rollback_step": (0, 1),
+    }
+
     def __init__(self, cfg, policy=None, use_streamed_mm=None):
         self.cfg = cfg
         self.policy = policy or NoPolicy()
@@ -65,31 +81,16 @@ class SubLayerEngine:
                                or os.environ.get("REPRO_STREAMED_FFN") == "1")
         self.use_streamed_mm = use_streamed_mm
         self._mm_interpret = jax.default_backend() != "tpu"
-        # donate the KV stacks on accelerators so the per-layer cache update
-        # is in-place; CPU ignores donation (and would warn), so skip there
-        donate = (2, 3) if jax.default_backend() != "cpu" else ()
-        self.attn_step = jax.jit(self._attn_step, donate_argnums=donate)
-        self.attn_prefill_step = jax.jit(self._attn_prefill_step,
-                                         donate_argnums=donate)
-        self.attn_decode_step = jax.jit(self._attn_decode_step,
-                                        donate_argnums=donate)
-        # slot-threaded prefill: writes ONE slot of the full stacked cache
-        # inside the donated jitted step, so serving admissions stop
-        # materialising a whole-cache copy per slot write (DESIGN.md §12)
-        self.attn_prefill_slot_step = jax.jit(self._attn_prefill_slot_step,
-                                              donate_argnums=donate)
-        # paged-KV steps (DESIGN.md §12): the cache is a physical page pool
-        # plus a per-layer page table; gather/scatter replace the stacked
-        # dynamic slices, everything downstream is the same attention math
-        self.attn_decode_paged_step = jax.jit(self._attn_decode_paged_step,
-                                              donate_argnums=donate)
-        self.attn_prefill_paged_step = jax.jit(self._attn_prefill_paged_step,
-                                               donate_argnums=donate)
-        donate_pools = (0, 1) if jax.default_backend() != "cpu" else ()
-        self.fold_page_step = jax.jit(self._fold_page_step,
-                                      donate_argnums=donate_pools)
-        self.rollback_step = jax.jit(self._rollback_step,
-                                     donate_argnums=donate_pools)
+        # dense FFN calls by the path they took: "jnp" or the Pallas kernel
+        # for the weight's storage format ("pallas_bf16/int8/int4")
+        self.ffn_paths = Counter()
+        # donate the KV stacks / page pools on accelerators so each cache
+        # update is in place; CPU ignores donation (and would warn)
+        donating = jax.default_backend() != "cpu"
+        for name, argnums in self.DONATED_ARGS.items():
+            setattr(self, name, jax.jit(getattr(self, "_" + name),
+                                        donate_argnums=argnums if donating
+                                        else ()))
         self._ffn_step_jit = jax.jit(self._ffn_step,
                                      static_argnames=("streamed",))
         self.moe_step = jax.jit(self._moe_step)
@@ -338,7 +339,15 @@ class SubLayerEngine:
         re-plan that newly streams FFNs (``rebind``, DESIGN.md §8) would
         trace a redundant variant of an identical computation."""
         streamed = streamed and self._streamed_mm_ok(x.shape, w["ffn"])
+        self.ffn_paths[self._ffn_path(w["ffn"]) if streamed else "jnp"] += 1
         return self._ffn_step_jit(w, x, streamed=streamed)
+
+    @staticmethod
+    def _ffn_path(p) -> str:
+        dt = p["w_up"].dtype
+        if dt == jnp.uint8:
+            return "pallas_int4"
+        return "pallas_int8" if dt == jnp.int8 else "pallas_bf16"
 
     def _ffn_step(self, w, x, streamed=False):
         self.trace_counts["ffn"] += 1

@@ -247,6 +247,14 @@ class PipelinedExecutor:
                  for k in keys},
                 jnp.asarray(0, jnp.int32))
 
+    @property
+    def end_bytes(self) -> int:
+        """Device bytes of the embed, final norm and output head, which the
+        executor holds outside the plan's pinned set (a tied head is a
+        transposed copy of the embedding)."""
+        return sum(a.nbytes for a in (self._embed_dev, self._final_dev,
+                                      self._unembed_dev))
+
     def _refresh_resident_expert_bytes(self):
         self.stats.resident_expert_bytes = sum(
             self._pinned_bytes[n] for n, k in self._pinned_kinds.items()
@@ -777,41 +785,52 @@ class PipelinedExecutor:
             self.prefetch.finish()
         self._sync_stats()
 
-    def _layer_loop(self, x, k, v, by_name, streaming, attn_fn):
+    def _layer_loop(self, x, k, v, by_name, streaming, attn_fn, live=None):
         """Walk every layer's (attn, ffn/moe) sub-layers under the current
         pass's plan: fetch weights (pinned / prefetched / at-use), account
         engine calls and boundary hops, run the sub-layer, release scratch
         slots. ``attn_fn(w, x, k, v, i)`` supplies the attention step —
-        chunked (`_attn_sub`) or fused decode (`attn_decode_step`)."""
+        chunked (`_attn_sub`) or fused decode (`attn_decode_step`).
+
+        ``live`` is the caller's stacked KV dict. On an accelerator the
+        attention steps donate their cache inputs, so when a pass fails
+        part-way the dict is handed the newest (live) buffers before the
+        exception propagates — a caller that survives the failure must
+        never read the donated, deleted ones."""
         cfg = self.cfg
         prev_engine = None
-        for i in range(cfg.n_layers):
-            pa = by_name[f"L{i}/attn"]
-            w, rel = self._weights_for(pa, streaming)
-            self.stats.engine_calls[pa.engine] += 1
-            if prev_engine is not None and prev_engine != pa.engine:
-                self.stats.boundary_hops += 1
-            prev_engine = pa.engine
-            x, k, v = attn_fn(w, x, k, v, i)
-            if rel:
-                self.prefetch.release(pa.sub.name)
-            if self.expert_granular:
-                pf = by_name[f"L{i}/moe.router"]
+        try:
+            for i in range(cfg.n_layers):
+                pa = by_name[f"L{i}/attn"]
+                w, rel = self._weights_for(pa, streaming)
+                self.stats.engine_calls[pa.engine] += 1
+                if prev_engine is not None and prev_engine != pa.engine:
+                    self.stats.boundary_hops += 1
+                prev_engine = pa.engine
+                x, k, v = attn_fn(w, x, k, v, i)
+                if rel:
+                    self.prefetch.release(pa.sub.name)
+                if self.expert_granular:
+                    pf = by_name[f"L{i}/moe.router"]
+                    if prev_engine != pf.engine:
+                        self.stats.boundary_hops += 1
+                    prev_engine = pf.engine
+                    x = self._moe_sub_granular(i, x, by_name, streaming)
+                    continue
+                pkey = f"L{i}/moe" if cfg.moe is not None else f"L{i}/ffn"
+                pf = by_name[pkey]
+                w, rel = self._weights_for(pf, streaming)
+                self.stats.engine_calls[pf.engine] += 1
                 if prev_engine != pf.engine:
                     self.stats.boundary_hops += 1
                 prev_engine = pf.engine
-                x = self._moe_sub_granular(i, x, by_name, streaming)
-                continue
-            pkey = f"L{i}/moe" if cfg.moe is not None else f"L{i}/ffn"
-            pf = by_name[pkey]
-            w, rel = self._weights_for(pf, streaming)
-            self.stats.engine_calls[pf.engine] += 1
-            if prev_engine != pf.engine:
-                self.stats.boundary_hops += 1
-            prev_engine = pf.engine
-            x = self._ffn_sub(w, x, streamed=pf.streamed)
-            if rel:
-                self.prefetch.release(pf.sub.name)
+                x = self._ffn_sub(w, x, streamed=pf.streamed)
+                if rel:
+                    self.prefetch.release(pf.sub.name)
+        except BaseException:
+            if live is not None:
+                live["k"], live["v"] = k, v
+            raise
         return x, k, v
 
     # ------------------------------------------------------------ forward
@@ -841,7 +860,8 @@ class PipelinedExecutor:
             x, k, v = self._layer_loop(
                 x, k, v, by_name, streaming,
                 lambda w, x, k, v, i: self._attn_sub(w, x, k, v, i, pos_arr,
-                                                     pos))
+                                                     pos),
+                live=kv if self.engine is not None else None)
             # slice the final position BEFORE the head: the (B, 1, d) shape
             # also matches the decode head call, so prefill shares its
             # executable instead of compiling a (B, T, d) variant per tier
@@ -914,7 +934,8 @@ class PipelinedExecutor:
                 x, k, v = self._layer_loop(
                     x, k, v, by_name, streaming,
                     lambda w, x, k, v, i: self.engine.attn_decode_step(
-                        w, x, k, v, self._layer_ids[i], pos_vec, active))
+                        w, x, k, v, self._layer_ids[i], pos_vec, active),
+                    live=kv)
             logits = self.engine.head_step(self._final_dev,
                                            self._unembed_dev, x)
         finally:
@@ -1026,7 +1047,7 @@ class PipelinedExecutor:
 
                 k, v = kv["k"], kv["v"]
                 x, k, v = self._layer_loop(x, k, v, by_name, streaming,
-                                           stacked_attn)
+                                           stacked_attn, live=kv)
             # unlike _run_chunk the head scores ALL W positions — the
             # acceptance loop needs the target's argmax at every one
             logits = self.engine.head_step(self._final_dev,
@@ -1301,6 +1322,12 @@ class PipelinedExecutor:
             x_last = xs[-1][:, tail - 1:tail]
             logits = eng.head_step(self._final_dev, self._unembed_dev,
                                    x_last)
+        except BaseException:
+            # hand the live stacked caches back, as _layer_loop does: the
+            # attention steps donated the caller's buffers on accelerators
+            if not paged and k is not None:
+                kv["k"], kv["v"] = k, v
+            raise
         finally:
             self._end_pass(started)
         self.stats.prefill_passes += 1

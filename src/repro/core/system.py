@@ -58,6 +58,21 @@ LOCAL = SystemConfig(
 
 SYSTEMS = {s.name: s for s in (CLI1, CLI2, CLI3, TPU_V5E, LOCAL)}
 
+# JAX ``device_kind`` -> the system row the planner prices for that chip.
+DEVICE_SYSTEMS = {"TPU v5 lite": TPU_V5E}
+
+
+def system_for_device_kind(kind: str) -> SystemConfig:
+    """The ``SystemConfig`` of an attached accelerator, by the
+    ``device_kind`` JAX reports. A kind without a row is an error, never a
+    default: planning one chip with another's constants is silently
+    wrong."""
+    try:
+        return DEVICE_SYSTEMS[kind]
+    except KeyError:
+        raise KeyError(f"no SystemConfig for device_kind {kind!r}; known: "
+                       f"{sorted(DEVICE_SYSTEMS)}") from None
+
 
 @dataclass(frozen=True)
 class InferenceSetting:

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 # Nominal quantisation group size along K (AWQ-style); balanced groups of
 # ceil(K / ceil(K / GROUP_SIZE)) rows are derived from it per matrix.
 GROUP_SIZE = 128
@@ -102,7 +100,7 @@ def streamed_matmul(x, w, *, block_m=128, block_n=128, block_k=512,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)
@@ -220,7 +218,7 @@ def streamed_matmul_int8(x, w_q, scales, *, block_m=128, block_n=128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_q, scales)
@@ -230,24 +228,29 @@ def _mm_int4_kernel(x_ref, w_ref, s_ref, z_ref, o_ref, acc_ref, *, n_k):
     """k-loop body with int4 dequant fused in: the packed bytes arrive in
     VMEM via the same double-buffered DMA as fp16 tiles; unpack, shift by
     the zero-point and scale all happen in-register before the MXU dot, so
-    no fp16 weight tile is ever materialised outside VMEM (DESIGN.md §11)."""
+    no fp16 weight tile is ever materialised outside VMEM (DESIGN.md §11).
+
+    Mosaic has no uint8 -> float conversion and no uint8 shift, so the
+    packed bytes widen to int32 before the nibble split; scales and
+    zero-points arrive as float32 (the wrapper converts the small (G, N)
+    arrays once per call)."""
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    p8 = w_ref[...]                          # (block_k // 2, block_n) uint8
-    half, bn = p8.shape
+    p32 = w_ref[...].astype(jnp.int32)       # (block_k // 2, block_n)
+    half, bn = p32.shape
     bk = 2 * half
-    lo = (p8 & 0xF).astype(jnp.float32)
-    hi = (p8 >> 4).astype(jnp.float32)
+    lo = (p32 & 0xF).astype(jnp.float32)
+    hi = ((p32 >> 4) & 0xF).astype(jnp.float32)
     q = jnp.stack([lo, hi], axis=1).reshape(bk, bn)
     gblk = s_ref.shape[0]                    # groups inside this k-block
     group = bk // gblk
-    s = jnp.broadcast_to(s_ref[...].astype(jnp.float32)[:, None, :],
+    s = jnp.broadcast_to(s_ref[:, 0, :][:, None, :],
                          (gblk, group, bn)).reshape(bk, bn)
-    z = jnp.broadcast_to(z_ref[...].astype(jnp.float32)[:, None, :],
+    z = jnp.broadcast_to(z_ref[:, 0, :][:, None, :],
                          (gblk, group, bn)).reshape(bk, bn)
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...].astype(jnp.float32), (q - z) * s,
@@ -268,6 +271,9 @@ def streamed_matmul_int4(x, w_packed, scales, zeros, *, block_m=128,
     ``block_k`` defaults to the quantisation group size (recovered from the
     scale shape) and must be a multiple of it, so each k-block holds whole
     groups and the in-kernel scale/zero broadcast is a static reshape.
+    Scales and zero-points enter the kernel as float32 ``(G, 1, N)``, so
+    their blocks ``(groups per k-block, 1, block_n)`` keep the last two
+    dims TPU-tileable whatever the group count per block.
     """
     M, K = x.shape
     Kh, N = w_packed.shape
@@ -285,6 +291,7 @@ def streamed_matmul_int4(x, w_packed, scales, zeros, *, block_m=128,
     assert M % block_m == 0 and N % block_n == 0 and K % block_k == 0
     assert block_k % group == 0 and block_k % 2 == 0
     n_k = K // block_k
+    gblk = block_k // group
     kernel = functools.partial(_mm_int4_kernel, n_k=n_k)
     return pl.pallas_call(
         kernel,
@@ -292,15 +299,14 @@ def streamed_matmul_int4(x, w_packed, scales, zeros, *, block_m=128,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_k // 2, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((block_k // group, block_n),
-                         lambda i, j, k: (k, j)),
-            pl.BlockSpec((block_k // group, block_n),
-                         lambda i, j, k: (k, j)),
+            pl.BlockSpec((gblk, 1, block_n), lambda i, j, k: (k, 0, j)),
+            pl.BlockSpec((gblk, 1, block_n), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w_packed, scales, zeros)
+    )(x, w_packed, scales.astype(jnp.float32).reshape(G, 1, N),
+      zeros.astype(jnp.float32).reshape(G, 1, N))

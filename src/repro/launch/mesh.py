@@ -6,18 +6,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat mesh constructor.
-
-    ``jax.sharding.AxisType`` (and ``jax.make_mesh``'s ``axis_types``
-    parameter) only exist from jax 0.5; on older runtimes every axis is
-    implicitly Auto, which is exactly what we request on newer ones — so
-    both branches build the same mesh.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """A mesh whose axes are all ``Auto`` (sharding propagated by XLA)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -9,15 +9,23 @@ VRAM-pressure scenario (DESIGN.md §8).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen30b-a3b \
         --hbm-budget-gb 4 --batch 4
+
+The planner's system row comes from the attached accelerator's
+``device_kind``; a device without a row (the CPU among them) needs one
+named, e.g. ``--system tpu-v5e``.
 """
 from __future__ import annotations
 
 import argparse
 import time
 
+import jax
+
 from repro import Session
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config, list_archs
-from repro.core import SYSTEMS, InferenceSetting, build_graph, run_install
+from repro.core import (SYSTEMS, InferenceSetting, build_graph, run_install,
+                        system_for_device_kind)
 from repro.core.serving import random_requests
 
 
@@ -26,14 +34,24 @@ def main():
     ap.add_argument("--arch", default="qwen30b-a3b",
                     choices=list_archs(include_paper=True))
     ap.add_argument("--hbm-budget-gb", type=float, default=4.0)
-    ap.add_argument("--system", default="tpu-v5e", choices=sorted(SYSTEMS))
+    ap.add_argument("--system", default="device",
+                    choices=["device"] + sorted(SYSTEMS),
+                    help="planner system row; 'device' looks it up from "
+                         "the attached accelerator's device_kind")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--context", type=int, default=4096)
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--new-tokens", type=int, default=8)
     args = ap.parse_args()
 
-    system = SYSTEMS[args.system]
+    enable_compile_cache()
+    if args.system == "device":
+        try:
+            system = system_for_device_kind(jax.devices()[0].device_kind)
+        except KeyError as e:
+            ap.error(f"{e.args[0]}; name a system with --system")
+    else:
+        system = SYSTEMS[args.system]
     budget = int(args.hbm_budget_gb * 1e9)
     db = run_install(system, quick=True)
 

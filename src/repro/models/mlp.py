@@ -23,12 +23,6 @@ from repro.kernels.streamed_matmul import (GROUP_SIZE, dequant_int4,
                                            quantize_int8)
 from repro.models.common import dense_init
 
-# jax.shard_map graduated from jax.experimental in 0.5; support both
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax<0.5 runtimes (e.g. CI 0.4.x)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 # ----------------------------------------------------- weight quantisation
 def quantize_weight_tree(p, weight_quant):
@@ -281,7 +275,7 @@ def moe_ffn_ep(params, cfg, x, policy):
     in_specs = (batch_spec, P()) + tuple(
         P(ep_axis, *([None] * (params[k].ndim - 1))) for k in wkeys)
 
-    @partial(_shard_map, mesh=mesh, in_specs=in_specs,
+    @partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
              out_specs=batch_spec)
     def _sharded(xl, router, *ws):
         rank = jax.lax.axis_index(ep_axis)

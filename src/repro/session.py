@@ -210,10 +210,19 @@ class Session:
                    setting or InferenceSetting(), **kw)
 
     # ------------------------------------------------------------ lazy build
+    @staticmethod
+    def _init_on_host(cfg, seed: int):
+        """Random parameters built on the host CPU device ("sysRAM"): the
+        executor copies them into its per-sub-layer host tree and puts on
+        the accelerator only what the plan pins or streams, so the device
+        never holds the whole model beside the pinned set."""
+        with jax.default_device(jax.devices("cpu")[0]):
+            return build_model(cfg).init(jax.random.PRNGKey(seed))
+
     @property
     def params(self):
         if self._params is None:
-            self._params = build_model(self.cfg).init(jax.random.PRNGKey(0))
+            self._params = self._init_on_host(self.cfg, 0)
         return self._params
 
     @property
@@ -224,8 +233,7 @@ class Session:
             # exercising the rollback path; callers wanting a high accept
             # rate pass the target's params (self-speculation) or real
             # draft weights explicitly
-            self._draft_params = build_model(self.draft_cfg).init(
-                jax.random.PRNGKey(1))
+            self._draft_params = self._init_on_host(self.draft_cfg, 1)
         return self._draft_params
 
     @property
@@ -618,6 +626,9 @@ class Session:
                 "rebind_pinned_bytes": ex.rebind_pinned_bytes,
                 "rebind_evicted_bytes": ex.rebind_evicted_bytes,
                 "rebind_s": ex.rebind_s,
+                # dense FFN calls by path: "jnp" or "pallas_<format>"
+                "ffn_paths": (dict(self._executor.engine.ffn_paths)
+                              if self._executor.engine is not None else {}),
             }
             if self.expert_granular:
                 out["executor"].update({

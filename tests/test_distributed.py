@@ -27,7 +27,6 @@ SCRIPT = textwrap.dedent("""
     from repro.config import ShapeConfig
 
     assert len(jax.devices()) == 8
-    # version-compat constructor: jax.sharding.AxisType only exists >= 0.5
     mesh = make_mesh((2, 4), ("data", "model"))
 
     for arch in ("qwen3-32b", "qwen3-moe-235b-a22b"):

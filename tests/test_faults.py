@@ -565,3 +565,54 @@ def test_healthz_reports_degradation(db):
     assert health["degradation_level"] == \
         DEGRADATION_RUNGS.index("tier_down")
     assert health["degradation_rung"] == "tier_down"
+
+
+# ============================================================ donation
+def _emulate_donation(eng):
+    """Make the engine's donating steps delete their KV inputs after use,
+    as buffer donation does on an accelerator — the CPU backend ignores
+    ``donate_argnums``, so a read of a donated buffer would go unseen."""
+    for name, argnums in eng.DONATED_ARGS.items():
+        def run(*args, _step=getattr(eng, name), _argnums=argnums):
+            out = _step(*args)
+            for i in _argnums:
+                args[i].delete()
+            return out
+        setattr(eng, name, run)
+
+
+@pytest.mark.parametrize("kv_layout", ["stacked", "paged"])
+def test_failed_prefill_keeps_live_kv_under_donation(db, kv_layout):
+    """A request whose prefill raises after its attention steps consumed
+    (donated) the shared cache fails alone: the batcher keeps serving the
+    other slots off the live buffers, bit-identical to a clean run, and
+    no step ever reads a donated buffer."""
+    cfg = get_smoke_config("yi-9b")
+    clean = dense_session(db, kv_layout=kv_layout)
+    ref = wave(cfg)
+    clean.serve(ref, max_batch=4)
+
+    sess = dense_session(db, kv_layout=kv_layout)
+    ex = sess.executor
+    _emulate_donation(ex.engine)
+    prefill, ffn_step = ex.prefill, ex.engine.ffn_step
+    state = {"calls": 0, "armed": False}
+
+    def counting_prefill(*a, **kw):
+        state["calls"] += 1
+        state["armed"] = state["calls"] == 2      # the second admission
+        return prefill(*a, **kw)
+
+    def failing_ffn(w, x, streamed=False):
+        if state["armed"]:
+            state["armed"] = False
+            raise RuntimeError("injected mid-prefill failure")
+        return ffn_step(w, x, streamed=streamed)
+
+    ex.prefill, ex.engine.ffn_step = counting_prefill, failing_ffn
+    reqs = wave(cfg)
+    sess.serve(reqs, max_batch=4)
+    assert [r.rid for r in sess.batcher().failed] == [reqs[1].rid]
+    for i in (0, 2):
+        assert reqs[i].error is None
+        assert reqs[i].generated == ref[i].generated
