@@ -121,7 +121,6 @@ def serve_phase(name, sess, *, seed=1, swap_to=None, require_stream=False,
     st = sess.stats()
     ex, deg, sv = st["executor"], st["degradation"], st["serving"]
     sched = sess.schedule
-    exe = sess.executor
     total = weight_bytes(cfg)
     decode_tier = sched.pick_decode_tier(BATCH)
     obs = {
@@ -141,7 +140,7 @@ def serve_phase(name, sess, *, seed=1, swap_to=None, require_stream=False,
         "plan_pinned_bytes": sched.pinned_bytes,
         "plan_scratch_bytes": sched.scratch_bytes,
         "plan_kv_bytes": sched.kv_pool_bytes,
-        "executor_end_bytes": exe.end_bytes,
+        "hbm_bytes": st["hbm_bytes"],
         "first_request_tokens": list(reqs[0].generated),
     }
     if swap:
@@ -167,7 +166,8 @@ def serve_phase(name, sess, *, seed=1, swap_to=None, require_stream=False,
     mem = device.memory_stats() if device is not None else None
     if mem:
         bound = (sched.pinned_bytes + sched.scratch_bytes
-                 + sched.kv_pool_bytes + exe.end_bytes + HBM_SLACK * total)
+                 + sched.kv_pool_bytes + st["hbm_bytes"]["outputs"]
+                 + HBM_SLACK * total)
         obs.update(bytes_in_use=mem.get("bytes_in_use"),
                    peak_bytes_in_use=mem.get("peak_bytes_in_use"),
                    hbm_bound=int(bound))

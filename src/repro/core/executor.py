@@ -22,6 +22,7 @@ optimal chunk size for chunked prefills").
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 
@@ -29,6 +30,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.costmodel import Placement
 from repro.core.engine import SubLayerEngine
@@ -36,7 +38,7 @@ from repro.core.faults import (DemandTimeout, FaultPlan, RecoveryPolicy,
                                WorkerLost)
 from repro.core.kvpaged import NULL_PAGE, PAGE_SIZE, PagedKVCache
 from repro.core.planner import Schedule
-from repro.core.prefetch import PrefetchEngine
+from repro.core.prefetch import PrefetchEngine, copy_to_device, tree_nbytes
 from repro.core.sublayer import SubLayer
 from repro.models import attention as attn_mod
 from repro.models import mlp as mlp_mod
@@ -50,6 +52,7 @@ class ExecStats:
     # from SubLayer.meta["quant"]) — the DESIGN.md §11 repricing surface
     streamed_bytes_by_dtype: dict = field(default_factory=dict)
     at_use_bytes: int = 0        # non-streamed (CPU-engine) at-use fetches
+    at_use_peak_bytes: int = 0   # largest tree fetched at use
     staged_bytes: int = 0        # actual host->device bytes moved
     copy_s_hidden: float = 0.0   # streamed copy time hidden under compute
     copy_s_exposed: float = 0.0  # streamed copy time compute waited on
@@ -188,6 +191,7 @@ class PipelinedExecutor:
         self.recovery = recovery if recovery is not None else RecoveryPolicy()
         self._sync_exposed = 0.0
         self._sync_staged = 0
+        self._pass_id = 0            # tags each pass's spans
         # split params into per-sublayer host copies ("sysRAM")
         self.host = {"embed": np.asarray(params["embed"]),
                      "final_norm": np.asarray(params["final_norm"])}
@@ -247,13 +251,30 @@ class PipelinedExecutor:
                  for k in keys},
                 jnp.asarray(0, jnp.int32))
 
-    @property
-    def end_bytes(self) -> int:
-        """Device bytes of the embed, final norm and output head, which the
-        executor holds outside the plan's pinned set (a tied head is a
-        transposed copy of the embedding)."""
-        return sum(a.nbytes for a in (self._embed_dev, self._final_dev,
-                                      self._unembed_dev))
+    def hbm_bytes(self, kv=None) -> dict:
+        """Device bytes held by each owner, counted from the arrays: the
+        plan's pinned sub-layers; the outputs the executor holds outside
+        the plan (embedding, final norm and head, where a tied head is a
+        transposed copy of the embedding); the KV cache ``kv`` the caller
+        serves from; the prefetcher's high-water of staged bytes not yet
+        released; and the largest tree fetched at use."""
+        if kv is None:
+            kv_arrays = []
+        elif isinstance(kv, PagedKVCache):
+            kv_arrays = [kv.k_pool, kv.v_pool]
+        else:
+            kv_arrays = jax.tree.leaves(kv)
+        return {
+            "pinned": sum(x.nbytes for tree in self._pinned.values()
+                          for x in jax.tree.leaves(tree)),
+            "outputs": sum(a.nbytes for a in (self._embed_dev,
+                                              self._final_dev,
+                                              self._unembed_dev)),
+            "kv": sum(x.nbytes for x in kv_arrays),
+            "scratch_peak": (self.prefetch.stats.scratch_peak_bytes
+                             if self.prefetch is not None else 0),
+            "at_use_peak": self.stats.at_use_peak_bytes,
+        }
 
     def _refresh_resident_expert_bytes(self):
         self.stats.resident_expert_bytes = sum(
@@ -281,23 +302,24 @@ class PipelinedExecutor:
         new_pins = {pl.sub.name: pl for pl in schedule.pinned_placements()}
         to_evict = [n for n in self._pinned if n not in new_pins]
         to_pin = [n for n in new_pins if n not in self._pinned]
-        evicted_bytes = 0
-        for name in to_evict:
-            del self._pinned[name]
-            del self._pinned_kinds[name]
-            evicted_bytes += self._pinned_bytes.pop(name)
-        pinned_bytes = 0
-        staged = []
-        for name in to_pin:
-            pl = new_pins[name]
-            tree = jax.device_put(self._subtree(pl.sub))
-            staged.append(tree)
-            self._pinned[name] = tree
-            self._pinned_bytes[name] = pl.sub.weight_bytes
-            self._pinned_kinds[name] = pl.sub.kind
-            pinned_bytes += pl.sub.weight_bytes
-        for tree in staged:
-            jax.block_until_ready(tree)
+        evicted_bytes = sum(self._pinned_bytes[n] for n in to_evict)
+        pinned_bytes = sum(new_pins[n].sub.weight_bytes for n in to_pin)
+        with TraceAnnotation("planner.rebind", pinned_bytes=pinned_bytes,
+                             evicted_bytes=evicted_bytes):
+            for name in to_evict:
+                del self._pinned[name]
+                del self._pinned_kinds[name]
+                del self._pinned_bytes[name]
+            staged = []
+            for name in to_pin:
+                pl = new_pins[name]
+                tree = jax.device_put(self._subtree(pl.sub))
+                staged.append(tree)
+                self._pinned[name] = tree
+                self._pinned_bytes[name] = pl.sub.weight_bytes
+                self._pinned_kinds[name] = pl.sub.kind
+            for tree in staged:
+                jax.block_until_ready(tree)
         self.schedule = schedule
         self._pinned_names = set(self._pinned)
         # per-layer pinned-expert weight stacks are views of the pin set:
@@ -354,16 +376,25 @@ class PipelinedExecutor:
             return cache.host_tree(sub.meta["bid"])
         raise ValueError(sub.kind)
 
+    def _copy_at_use(self, sub, tree, nbytes: int):
+        """The consumer's own transfer of ``sub``'s host ``tree``, waited
+        on: the ``executor.fetch_at_use`` span."""
+        with TraceAnnotation("executor.fetch_at_use", sub=sub.name,
+                             bytes=nbytes):
+            dev = copy_to_device(tree, nbytes)
+        self._sync_staged += nbytes
+        self.stats.at_use_peak_bytes = max(self.stats.at_use_peak_bytes,
+                                           nbytes)
+        return dev
+
     def _fetch_sync(self, placement):
         """Synchronous at-use transfer (CPU-engine placements, and every
         streamed placement when overlap is disabled)."""
         tree = self._subtree(placement.sub)
-        nbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+        nbytes = tree_nbytes(tree)
         t0 = time.perf_counter()
-        dev = jax.device_put(tree)
-        jax.block_until_ready(dev)
+        dev = self._copy_at_use(placement.sub, tree, nbytes)
         dt = time.perf_counter() - t0
-        self._sync_staged += nbytes
         if placement.streamed and placement.engine == "gpu":
             self._account_streamed(placement)
             self._sync_exposed += dt
@@ -396,11 +427,7 @@ class PipelinedExecutor:
         plan-priced bytes were already (or will be) accounted by the
         caller, so a retried shard lands in the ledger exactly once."""
         host = self._subtree(sub)
-        tree = jax.device_put(host)
-        jax.block_until_ready(tree)
-        self._sync_staged += sum(
-            x.size * x.dtype.itemsize for x in jax.tree.leaves(host))
-        return tree
+        return self._copy_at_use(sub, host, tree_nbytes(host))
 
     # ------------------------------------------------------------ recovery
     def _note_stream_fault(self, exc: Exception):
@@ -776,14 +803,32 @@ class PipelinedExecutor:
         if started:
             self.prefetch.start(
                 order, avail_bytes=max(entry.scratch_bytes - entry.act_bytes,
-                                       0), demand_bytes=demand_bytes)
+                                       0), demand_bytes=demand_bytes,
+                pass_id=self._pass_id)
             self._demand_active = demand_bytes > 0
         return by_name, streaming, started
 
-    def _end_pass(self, started: bool):
-        if started:
-            self.prefetch.finish()
-        self._sync_stats()
+    @contextlib.contextmanager
+    def _pass(self, kind: str, tier: int, page_demand_bytes: int = 0):
+        """One pass over ``tier``'s plan inside an ``executor.pass`` span:
+        begins it (``_begin_pass``), yields ``(by_name, streaming,
+        started)``, and on the way out, raised or not, joins the prefetch
+        session (``executor.pass_end``) and drops the pass's paged
+        cache."""
+        self._pass_id += 1
+        with TraceAnnotation("executor.pass", kind=kind, tier=tier,
+                             pass_id=self._pass_id):
+            by_name, streaming, started = self._begin_pass(
+                tier, page_demand_bytes=page_demand_bytes)
+            try:
+                yield by_name, streaming, started
+            finally:
+                if started:
+                    with TraceAnnotation("executor.pass_end",
+                                         pass_id=self._pass_id):
+                        self.prefetch.finish()
+                self._sync_stats()
+                self._active_kvcache = None
 
     def _layer_loop(self, x, k, v, by_name, streaming, attn_fn, live=None):
         """Walk every layer's (attn, ffn/moe) sub-layers under the current
@@ -845,9 +890,8 @@ class PipelinedExecutor:
         """
         cfg = self.cfg
         self._check_alloc("chunk")
-        by_name, streaming, started = self._begin_pass(
-            self.schedule.pick_tier(tokens.shape[0] * tokens.shape[1]))
-        try:
+        tier = self.schedule.pick_tier(tokens.shape[0] * tokens.shape[1])
+        with self._pass("chunk", tier) as (by_name, streaming, _):
             if self.engine is not None:
                 x = self.engine.embed_step(self._embed_dev, tokens)
                 k, v = kv["k"], kv["v"]
@@ -871,8 +915,6 @@ class PipelinedExecutor:
             else:
                 xl = rmsnorm(x[:, -1:], self._final_dev, cfg.norm_eps)
                 logits = xl @ self._unembed_dev
-        finally:
-            self._end_pass(started)
         if self.engine is None:
             k, v = jnp.stack(k), jnp.stack(v)
         return logits, {"k": k, "v": v}
@@ -905,17 +947,16 @@ class PipelinedExecutor:
                                         if act_h[s]})
             page_demand = kv.block_bytes if faults else 0
             self._active_kvcache = kv
-        by_name, streaming, started = self._begin_pass(
-            self.schedule.pick_decode_tier(
-                n_active, queue_depth=self.sched_queue_depth,
-                slack_s=self.sched_slack_s),
-            page_demand_bytes=page_demand)
-        page_stream = paged and started and self._demand_active
+        tier = self.schedule.pick_decode_tier(
+            n_active, queue_depth=self.sched_queue_depth,
+            slack_s=self.sched_slack_s)
         streamed_before = self.stats.streamed_bytes
         demanded_before = (self.stats.expert_demanded,
                            self.stats.expert_hits,
                            self.stats.demanded_expert_bytes)
-        try:
+        with self._pass("decode", tier, page_demand) as (by_name, streaming,
+                                                         started):
+            page_stream = paged and started and self._demand_active
             x = self.engine.embed_step(self._embed_dev, tokens)
             if paged:
                 def paged_attn(w, x, k, v, i):
@@ -938,9 +979,6 @@ class PipelinedExecutor:
                     live=kv)
             logits = self.engine.head_step(self._final_dev,
                                            self._unembed_dev, x)
-        finally:
-            self._end_pass(started)
-            self._active_kvcache = None
         self.stats.decode_passes += 1
         self.stats.pass_streamed_bytes.append(
             self.stats.streamed_bytes - streamed_before)
@@ -1002,9 +1040,6 @@ class PipelinedExecutor:
         tier = self.schedule.pick_decode_tier(
             n_active * W, queue_depth=self.sched_queue_depth,
             slack_s=self.sched_slack_s)
-        by_name, streaming, started = self._begin_pass(
-            tier, page_demand_bytes=page_demand)
-        page_stream = paged and started and self._demand_active
         streamed_before = self.stats.streamed_bytes
         expert_bytes_before = self.stats.demanded_expert_bytes
         page_bytes_before = self.stats.demanded_page_bytes
@@ -1014,7 +1049,9 @@ class PipelinedExecutor:
             p.sub.weight_bytes
             for p in self.schedule.tiers[tier].plan.static_stream_order()
             if p.sub.name not in self._pinned_names)
-        try:
+        with self._pass("verify", tier, page_demand) as (by_name, streaming,
+                                                         started):
+            page_stream = paged and started and self._demand_active
             x = self.engine.embed_step(self._embed_dev, tokens)
             if paged:
                 def paged_attn(w, x, k, v, i):
@@ -1052,9 +1089,6 @@ class PipelinedExecutor:
             # acceptance loop needs the target's argmax at every one
             logits = self.engine.head_step(self._final_dev,
                                            self._unembed_dev, x)
-        finally:
-            self._end_pass(started)
-            self._active_kvcache = None
         self.stats.spec_verify_passes += 1
         self.stats.verify_pass_stats.append({
             "width": W,
@@ -1247,89 +1281,89 @@ class PipelinedExecutor:
         pad = C * chunk - T if pad_ok else 0
         if pad:
             tokens = jnp.pad(tokens, ((0, 0), (0, pad)))
-        by_name, streaming, started = self._begin_pass(
-            tier, page_demand_bytes=page_demand)
-        page_stream = paged and started and self._demand_active
         slot_arr = None if slot is None else jnp.asarray(slot, jnp.int32)
-        try:
-            k = v = None
-            if not paged:
-                k, v = kv["k"], kv["v"]
-            xs = [eng.embed_step(self._embed_dev,
-                                 tokens[:, c * chunk:
-                                        min((c + 1) * chunk, tokens.shape[1])])
-                  for c in range(C)]
-            pos_c = [jnp.asarray(pos0 + c * chunk, jnp.int32)
-                     for c in range(C)]
-            valid_c = [jnp.asarray(chunk if c < C - 1 else tail, jnp.int32)
-                       for c in range(C)]
-            prev_engine = None
-            for i in range(cfg.n_layers):
-                pa = by_name[f"L{i}/attn"]
-                w, rel = self._weights_for(pa, streaming)
-                self.stats.engine_calls[pa.engine] += C
-                if prev_engine is not None and prev_engine != pa.engine:
-                    self.stats.boundary_hops += 1
-                prev_engine = pa.engine
-                if paged:
-                    # restore this layer's faulted blocks, then run every
-                    # chunk against the layer's physical page table
-                    self._page_fault_layer(kv, i, page_stream)
-                    table = kv.layer_table(i, rows=rows)
-                    for c in range(C):
-                        xs[c], kv.k_pool, kv.v_pool = \
-                            eng.attn_prefill_paged_step(
-                                w, xs[c], kv.k_pool, kv.v_pool, table,
+        with self._pass("prefill", tier, page_demand) as (by_name, streaming,
+                                                          started):
+            page_stream = paged and started and self._demand_active
+            try:
+                k = v = None
+                if not paged:
+                    k, v = kv["k"], kv["v"]
+                xs = [eng.embed_step(
+                          self._embed_dev,
+                          tokens[:, c * chunk:min((c + 1) * chunk,
+                                                  tokens.shape[1])])
+                      for c in range(C)]
+                pos_c = [jnp.asarray(pos0 + c * chunk, jnp.int32)
+                         for c in range(C)]
+                valid_c = [jnp.asarray(chunk if c < C - 1 else tail, jnp.int32)
+                           for c in range(C)]
+                prev_engine = None
+                for i in range(cfg.n_layers):
+                    pa = by_name[f"L{i}/attn"]
+                    w, rel = self._weights_for(pa, streaming)
+                    self.stats.engine_calls[pa.engine] += C
+                    if prev_engine is not None and prev_engine != pa.engine:
+                        self.stats.boundary_hops += 1
+                    prev_engine = pa.engine
+                    if paged:
+                        # restore this layer's faulted blocks, then run every
+                        # chunk against the layer's physical page table
+                        self._page_fault_layer(kv, i, page_stream)
+                        table = kv.layer_table(i, rows=rows)
+                        for c in range(C):
+                            xs[c], kv.k_pool, kv.v_pool = \
+                                eng.attn_prefill_paged_step(
+                                    w, xs[c], kv.k_pool, kv.v_pool, table,
+                                    pos_c[c], valid_c[c])
+                        kv.end_layer(i)
+                    elif slot is not None:
+                        for c in range(C):
+                            xs[c], k, v = eng.attn_prefill_slot_step(
+                                w, xs[c], k, v, self._layer_ids[i], slot_arr,
                                 pos_c[c], valid_c[c])
-                    kv.end_layer(i)
-                elif slot is not None:
-                    for c in range(C):
-                        xs[c], k, v = eng.attn_prefill_slot_step(
-                            w, xs[c], k, v, self._layer_ids[i], slot_arr,
-                            pos_c[c], valid_c[c])
-                else:
-                    for c in range(C):
-                        xs[c], k, v = eng.attn_prefill_step(
-                            w, xs[c], k, v, self._layer_ids[i], pos_c[c],
-                            valid_c[c])
-                if rel:
-                    self.prefetch.release(pa.sub.name)
-                if self.expert_granular:
-                    pf = by_name[f"L{i}/moe.router"]
+                    else:
+                        for c in range(C):
+                            xs[c], k, v = eng.attn_prefill_step(
+                                w, xs[c], k, v, self._layer_ids[i], pos_c[c],
+                                valid_c[c])
+                    if rel:
+                        self.prefetch.release(pa.sub.name)
+                    if self.expert_granular:
+                        pf = by_name[f"L{i}/moe.router"]
+                        if prev_engine != pf.engine:
+                            self.stats.boundary_hops += 1
+                        prev_engine = pf.engine
+                        xs = self._moe_layer_granular_chunks(
+                            i, xs, valid_c, by_name, streaming)
+                        continue
+                    pkey = f"L{i}/moe" if cfg.moe is not None else f"L{i}/ffn"
+                    pf = by_name[pkey]
+                    w, rel = self._weights_for(pf, streaming)
+                    self.stats.engine_calls[pf.engine] += C
                     if prev_engine != pf.engine:
                         self.stats.boundary_hops += 1
                     prev_engine = pf.engine
-                    xs = self._moe_layer_granular_chunks(
-                        i, xs, valid_c, by_name, streaming)
-                    continue
-                pkey = f"L{i}/moe" if cfg.moe is not None else f"L{i}/ffn"
-                pf = by_name[pkey]
-                w, rel = self._weights_for(pf, streaming)
-                self.stats.engine_calls[pf.engine] += C
-                if prev_engine != pf.engine:
-                    self.stats.boundary_hops += 1
-                prev_engine = pf.engine
-                for c in range(C):
-                    if cfg.moe is not None:
-                        xs[c] = eng.moe_prefill_step(w, xs[c], valid_c[c])
-                    else:
-                        xs[c] = eng.ffn_step(w, xs[c], streamed=pf.streamed)
-                if rel:
-                    self.prefetch.release(pf.sub.name)
-            # final logits from the last VALID position only (the padded
-            # rows are garbage); (B, 1, d) shares the decode head
-            # executable
-            x_last = xs[-1][:, tail - 1:tail]
-            logits = eng.head_step(self._final_dev, self._unembed_dev,
-                                   x_last)
-        except BaseException:
-            # hand the live stacked caches back, as _layer_loop does: the
-            # attention steps donated the caller's buffers on accelerators
-            if not paged and k is not None:
-                kv["k"], kv["v"] = k, v
-            raise
-        finally:
-            self._end_pass(started)
+                    for c in range(C):
+                        if cfg.moe is not None:
+                            xs[c] = eng.moe_prefill_step(w, xs[c], valid_c[c])
+                        else:
+                            xs[c] = eng.ffn_step(w, xs[c],
+                                                 streamed=pf.streamed)
+                    if rel:
+                        self.prefetch.release(pf.sub.name)
+                # final logits from the last VALID position only (the padded
+                # rows are garbage); (B, 1, d) shares the decode head
+                # executable
+                x_last = xs[-1][:, tail - 1:tail]
+                logits = eng.head_step(self._final_dev, self._unembed_dev,
+                                       x_last)
+            except BaseException:
+                # hand the live stacked caches back, as _layer_loop does: the
+                # attention steps donated the caller's buffers on accelerators
+                if not paged and k is not None:
+                    kv["k"], kv["v"] = k, v
+                raise
         self.stats.prefill_passes += 1
         # the realised activation ring: every chunk's residual held at
         # once, ~one full-prompt residual (DESIGN.md §10 accounting)

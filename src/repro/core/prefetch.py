@@ -60,9 +60,23 @@ from dataclasses import dataclass
 from typing import Callable, List, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.faults import (DemandTimeout, FaultPlan, RecoveryPolicy,
                                WorkerLost)
+
+
+def tree_nbytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def copy_to_device(tree, nbytes: int):
+    """One host-to-HBM transfer, waited on: the ``link.copy`` span, on
+    whichever thread makes it (a staging worker or the consumer)."""
+    with TraceAnnotation("link.copy", bytes=nbytes):
+        dev = jax.device_put(tree)
+        jax.block_until_ready(dev)
+    return dev
 
 
 @dataclass
@@ -79,11 +93,12 @@ class PrefetchStats:
     copy_failures: int = 0       # stage copies that exhausted their retries
     worker_crashes: int = 0      # transfer threads that died (DESIGN.md §15)
     abandoned: int = 0           # demand entries dropped past their deadline
+    scratch_peak_bytes: int = 0  # high-water of staged bytes not yet released
 
 
 class _Staged:
     __slots__ = ("event", "tree", "copy_s", "error", "pool", "abandoned",
-                 "holds_slot")
+                 "holds_slot", "held_bytes")
 
     def __init__(self, pool: str = "static"):
         self.event = threading.Event()
@@ -96,6 +111,9 @@ class _Staged:
         # this entry — a WorkerLost-failed entry never held one, so the
         # discard/finish paths know whether a release is owed
         self.holds_slot = False
+        # staged bytes this entry counts in the engine's scratch total
+        # until it is released, discarded or abandoned
+        self.held_bytes = 0
 
 
 class PrefetchEngine:
@@ -122,6 +140,8 @@ class PrefetchEngine:
         self._demand_cv = threading.Condition()
         self._lock = threading.Lock()  # guards _Staged event/abandoned races
         self._closed = True
+        self._held_bytes = 0
+        self._pass_id = 0
         self.worker_error: Optional[WorkerLost] = None
         self.demand_worker_error: Optional[WorkerLost] = None
 
@@ -146,7 +166,7 @@ class PrefetchEngine:
         return 2 if avail_bytes >= 2 * max_w else 1
 
     def start(self, order: List, avail_bytes: Optional[int] = None,
-              demand_bytes: int = 0):
+              demand_bytes: int = 0, pass_id: int = 0):
         """Begin staging ``order`` (Placement list) one sub-layer ahead.
 
         Every item of ``order`` MUST be acquire()d and release()d by the
@@ -156,7 +176,8 @@ class PrefetchEngine:
         ``demand_bytes > 0`` additionally opens the session for mid-pass
         ``request()`` calls (demand-streamed expert shards, DESIGN.md §9);
         the value is the largest shard a request may carry, used to size
-        the demand slot pool.
+        the demand slot pool. ``pass_id`` tags the session's
+        ``prefetch.stage`` spans with the consumer's pass.
         """
         assert not self.active, "prefetch session already active"
         if not order and demand_bytes <= 0:
@@ -166,6 +187,7 @@ class PrefetchEngine:
         self.stats.slots = self.slots_for(order, avail_bytes)
         self._sem = threading.Semaphore(self.stats.slots)
         self._staged = {n: _Staged() for n in names}
+        self._pass_id = pass_id
         self._closed = False
         self.worker_error = None
         self.demand_worker_error = None
@@ -205,16 +227,18 @@ class PrefetchEngine:
         attempt = 0
         while True:
             try:
-                t0 = time.perf_counter()
-                if self.faults is not None:
-                    self.faults.check(point, key=pl.sub.name)
-                host = self._fetch_host(pl.sub)
-                dev = jax.device_put(host)
-                jax.block_until_ready(dev)
-                st.copy_s = time.perf_counter() - t0
+                with TraceAnnotation("prefetch.stage", sub=pl.sub.name,
+                                     bytes=pl.sub.weight_bytes, pool=st.pool,
+                                     pass_id=self._pass_id, attempt=attempt):
+                    t0 = time.perf_counter()
+                    if self.faults is not None:
+                        self.faults.check(point, key=pl.sub.name)
+                    host = self._fetch_host(pl.sub)
+                    nbytes = tree_nbytes(host)
+                    dev = copy_to_device(host, nbytes)
+                    st.copy_s = time.perf_counter() - t0
                 st.tree = dev
-                self.stats.staged_bytes += sum(
-                    x.size * x.dtype.itemsize for x in jax.tree.leaves(host))
+                self.stats.staged_bytes += nbytes
                 self.stats.staged_sublayers += 1
                 break
             except BaseException as e:
@@ -231,6 +255,17 @@ class PrefetchEngine:
                 st.tree = None
                 (self._demand_sem if st.pool == "demand"
                  else self._sem).release()
+            elif st.tree is not None:
+                st.held_bytes = nbytes
+                self._held_bytes += nbytes
+                self.stats.scratch_peak_bytes = max(
+                    self.stats.scratch_peak_bytes, self._held_bytes)
+
+    def _drop_held(self, st: _Staged):
+        """Take a released entry's bytes off the scratch total (caller
+        holds ``_lock``)."""
+        self._held_bytes -= st.held_bytes
+        st.held_bytes = 0
 
     def _worker(self, order):
         try:
@@ -316,9 +351,10 @@ class PrefetchEngine:
         must then ``abandon(name)`` (never release) and fetch the shard
         itself, so a wedged transfer can never deadlock the pass."""
         st = self._staged[name]
-        t0 = time.perf_counter()
-        staged = st.event.wait(timeout)
-        exposed = time.perf_counter() - t0
+        with TraceAnnotation("prefetch.acquire", sub=name):
+            t0 = time.perf_counter()
+            staged = st.event.wait(timeout)
+            exposed = time.perf_counter() - t0
         if not staged:
             raise DemandTimeout(
                 f"{name} not staged within {timeout:.3f}s")
@@ -332,6 +368,8 @@ class PrefetchEngine:
         """Free ``name``'s scratch slot (compute for it has been issued)."""
         st = self._staged.pop(name)
         st.tree = None
+        with self._lock:
+            self._drop_held(st)
         (self._demand_sem if st.pool == "demand" else self._sem).release()
 
     def discard(self, name: str):
@@ -345,6 +383,7 @@ class PrefetchEngine:
         with self._lock:
             st = self._staged.pop(name)
             st.tree = None
+            self._drop_held(st)
             if st.holds_slot:
                 (self._demand_sem if st.pool == "demand"
                  else self._sem).release()
@@ -360,6 +399,7 @@ class PrefetchEngine:
             self.stats.abandoned += 1
             if st.event.is_set():
                 st.tree = None
+                self._drop_held(st)
                 (self._demand_sem if st.pool == "demand"
                  else self._sem).release()
 

@@ -26,6 +26,7 @@ from typing import List, Optional
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.executor import PipelinedExecutor
 from repro.core.faults import AllocationFault
@@ -246,7 +247,9 @@ class ContinuousBatcher:
                 # unwritten KV cache)
                 self._validate(req)
                 self.slots[i] = req
-                self._prefill_guard(i, req)
+                with TraceAnnotation("serving.admit", rid=req.rid,
+                                     prompt_len=len(req.prompt)):
+                    self._prefill_guard(i, req)
 
     def _validate(self, req: Request):
         T = len(req.prompt)
@@ -294,18 +297,19 @@ class ContinuousBatcher:
             # §14); kept even while spec_k is 0 (rebudget-disabled) so a
             # later re-enable finds the prompt prefix in place
             self.spec.prefill_slot(slot, req.prompt)
-        nxt = int(greedy_token(logits[0, -1]))
-        req.generated.append(nxt)
-        req.first_token_at = time.perf_counter()
-        req.pos = T
-        self.last_tokens = self.last_tokens.at[slot, 0].set(nxt)
-        self._events.append(TokenEvent(req.rid, nxt, len(req.generated) - 1,
-                                       req.done))
-        # a request whose budget is a single token finishes on its prefill
-        # token: retire it here so its slot frees immediately and done_at is
-        # recorded exactly like a decode-phase completion
-        if req.done:
-            self._retire(slot)
+        with TraceAnnotation("serving.sample"):
+            nxt = int(greedy_token(logits[0, -1]))
+            req.generated.append(nxt)
+            req.first_token_at = time.perf_counter()
+            req.pos = T
+            self.last_tokens = self.last_tokens.at[slot, 0].set(nxt)
+            self._events.append(TokenEvent(req.rid, nxt,
+                                           len(req.generated) - 1, req.done))
+            # a request whose budget is a single token finishes on its
+            # prefill token: retire it here so its slot frees immediately
+            # and done_at is recorded exactly like a decode-phase completion
+            if req.done:
+                self._retire(slot)
 
     def _prefill_guard(self, slot: int, req: Request):
         """Admission under fault protection (DESIGN.md §15). An allocation
@@ -396,12 +400,13 @@ class ContinuousBatcher:
             return
         before = self.ex.stats.streamed_bytes
         moved_before = self.ex.stats.staged_bytes
-        if self.spec_k > 0:
-            self._decode_spec(active)
-        elif self.fused:
-            self._decode_fused(active)
-        else:
-            self._decode_per_slot(active)
+        with TraceAnnotation("serving.decode", active=len(active)):
+            if self.spec_k > 0:
+                self._decode_spec(active)
+            elif self.fused:
+                self._decode_fused(active)
+            else:
+                self._decode_per_slot(active)
         self.iter_streamed_bytes.append(self.ex.stats.streamed_bytes - before)
         self.iter_moved_bytes.append(self.ex.stats.staged_bytes
                                      - moved_before)
@@ -421,9 +426,10 @@ class ContinuousBatcher:
         logits, self.kv = self.ex._run_decode(
             self.last_tokens, self.kv, jnp.asarray(pos_vec),
             jnp.asarray(mask), n_active=len(active))
-        nxt = np.asarray(greedy_token(logits[:, -1]))
-        for i in active:
-            self._advance_guard(i, int(nxt[i]))
+        with TraceAnnotation("serving.sample"):
+            nxt = np.asarray(greedy_token(logits[:, -1]))
+            for i in active:
+                self._advance_guard(i, int(nxt[i]))
 
     def _seq_token(self, req: Request, idx: int) -> int:
         """Committed sequence token at index ``idx``: prompt positions
@@ -478,36 +484,37 @@ class ContinuousBatcher:
         logits, self.kv = self.ex._run_verify(
             jnp.asarray(tokens), self.kv, jnp.asarray(pos_vec),
             jnp.asarray(mask), n_active=len(active))
-        targets = np.asarray(greedy_token(logits))  # (B, W)
-        keep_pos = np.zeros((B,), np.int32)
-        roll_mask = np.zeros((B,), bool)
-        st = self.ex.stats
-        for i in active:
-            r = self.slots[i]
-            # longest accepted draft prefix: d_{j+1} == target's greedy
-            # continuation t_j over the identical committed context
-            a = 0
-            while a < k and drafts[i, a] == targets[i, a]:
-                a += 1
-            remaining = r.max_new_tokens - len(r.generated)
-            e = min(a + 1, remaining)
-            st.spec_drafted += k
-            st.spec_accepted += e - 1  # bonus token not counted
-            for j in range(e):
-                if self.slots[i] is None:
-                    # _advance_guard failed the slot mid-commit — the
-                    # remaining accepted tokens die with the request
-                    break
-                self._advance_guard(i, int(targets[i, j]))
-            if e < W:
-                st.spec_rollbacks += 1
-                st.spec_rolled_back_tokens += W - e
-                if self.slots[i] is not None:
-                    keep_pos[i] = pos_vec[i] + e
-                    roll_mask[i] = True
-                # a retired slot needs no rollback: paged free_slot
-                # already released its blocks; a stacked slot's stale
-                # tail is masked until the next admission overwrites it
+        with TraceAnnotation("serving.sample"):
+            targets = np.asarray(greedy_token(logits))  # (B, W)
+            keep_pos = np.zeros((B,), np.int32)
+            roll_mask = np.zeros((B,), bool)
+            st = self.ex.stats
+            for i in active:
+                r = self.slots[i]
+                # longest accepted draft prefix: d_{j+1} == target's greedy
+                # continuation t_j over the identical committed context
+                a = 0
+                while a < k and drafts[i, a] == targets[i, a]:
+                    a += 1
+                remaining = r.max_new_tokens - len(r.generated)
+                e = min(a + 1, remaining)
+                st.spec_drafted += k
+                st.spec_accepted += e - 1  # bonus token not counted
+                for j in range(e):
+                    if self.slots[i] is None:
+                        # _advance_guard failed the slot mid-commit — the
+                        # remaining accepted tokens die with the request
+                        break
+                    self._advance_guard(i, int(targets[i, j]))
+                if e < W:
+                    st.spec_rollbacks += 1
+                    st.spec_rolled_back_tokens += W - e
+                    if self.slots[i] is not None:
+                        keep_pos[i] = pos_vec[i] + e
+                        roll_mask[i] = True
+                    # a retired slot needs no rollback: paged free_slot
+                    # already released its blocks; a stacked slot's stale
+                    # tail is masked until the next admission overwrites it
         if roll_mask.any():
             self.kv = self.ex.rollback_kv(self.kv, keep_pos, roll_mask)
 
@@ -591,28 +598,32 @@ class ContinuousBatcher:
         iteration instead of at batch completion."""
         self._events = []
         t0 = time.perf_counter()
-        if self._queue_aware:
-            self._apply_queue_hints(admitting=True)
-        self._admit(self.pending)
-        if self._queue_aware:
-            self._apply_queue_hints(admitting=False)
-        while True:
-            try:
-                self._decode_iteration()
-                break
-            except (AllocationFault, PagePoolFull) as e:
-                # emergency-rebudget ladder (DESIGN.md §15): degrade one
-                # rung and re-run the iteration. The failed attempt aborted
-                # before its KV writes (alloc checks fire at pass entry),
-                # and a re-run writes the same tokens at the same
-                # positions, so the retry is bit-identical.
-                self._degrade_or_raise(e)
-        self.iterations += 1
-        if self._session is not None and self.ex.stats.degraded_sync:
-            # watchdog propagation: a prefetch-worker death already flipped
-            # the executor to the sync path; let the session record the
-            # terminal ladder rung so stats()/metrics report it
-            self._session.note_executor_degraded()
+        with TraceAnnotation("serving.step", iteration=self.iterations,
+                             active=sum(s is not None for s in self.slots),
+                             pending=len(self.pending)):
+            if self._queue_aware:
+                self._apply_queue_hints(admitting=True)
+            self._admit(self.pending)
+            if self._queue_aware:
+                self._apply_queue_hints(admitting=False)
+            while True:
+                try:
+                    self._decode_iteration()
+                    break
+                except (AllocationFault, PagePoolFull) as e:
+                    # emergency-rebudget ladder (DESIGN.md §15): degrade
+                    # one rung and re-run the iteration. The failed attempt
+                    # aborted before its KV writes (alloc checks fire at
+                    # pass entry), and a re-run writes the same tokens at
+                    # the same positions, so the retry is bit-identical.
+                    self._degrade_or_raise(e)
+            self.iterations += 1
+            if self._session is not None and self.ex.stats.degraded_sync:
+                # watchdog propagation: a prefetch-worker death already
+                # flipped the executor to the sync path; let the session
+                # record the terminal ladder rung so stats()/metrics
+                # report it
+                self._session.note_executor_degraded()
         self._serve_wall_s += time.perf_counter() - t0
         return self._events
 

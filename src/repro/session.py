@@ -595,6 +595,11 @@ class Session:
                "spec_active": self.spec_active,
                "draft_carve_bytes": self.draft_carve_bytes}
         if self._executor is not None:
+            # device bytes by owner, counted from the arrays themselves
+            # (the plan's pinned set, the outputs held beside it, the
+            # batcher's KV cache, scratch and at-use high-waters)
+            out["hbm_bytes"] = self._executor.hbm_bytes(
+                kv=self._batcher.kv if self._batcher is not None else None)
             ex = self._executor.stats
             pf = ex.prefill_stats
             out["executor"] = {
