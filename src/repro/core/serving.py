@@ -21,10 +21,12 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import List, Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
@@ -33,6 +35,25 @@ from repro.core.faults import AllocationFault
 from repro.core.kvpaged import PagedKVCache, PagePoolFull
 from repro.core.planner import Schedule
 from repro.models.common import greedy_token
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _greedy(rule, last_only: bool, logits):
+    """A greedy pick as one executable per logits shape: eager, the slice
+    and the casts around the argmax dispatch ~8 tiny executables. The rule
+    is a static argument, this module's ``greedy_token`` looked up at each
+    call, so the f32 upcast and lowest-index ties are the shared ones."""
+    return rule(logits[:, -1] if last_only else logits)
+
+
+def _pick_last(logits):
+    """(B, T, V) logits -> (B,) greedy tokens at each row's last position."""
+    return _greedy(greedy_token, True, logits)
+
+
+def _pick_all(logits):
+    """(B, W, V) logits -> (B, W) greedy tokens at every position."""
+    return _greedy(greedy_token, False, logits)
 
 
 @dataclass
@@ -175,7 +196,9 @@ class ContinuousBatcher:
         self._queue_aware = False
         self._queue_depth = 0
         self._slack_s: Optional[float] = None
-        self.last_tokens = jnp.zeros((max_batch, 1), jnp.int32)
+        # each slot's last token, on the host: a commit is a host write and
+        # a pass uploads one copy (_tokens_dev), so no token costs a launch
+        self.last_tokens = np.zeros((max_batch, 1), np.int32)
         self.iterations = 0
         self.tier_log = []
         self.completed: List[Request] = []
@@ -298,11 +321,11 @@ class ContinuousBatcher:
             # later re-enable finds the prompt prefix in place
             self.spec.prefill_slot(slot, req.prompt)
         with TraceAnnotation("serving.sample"):
-            nxt = int(greedy_token(logits[0, -1]))
+            nxt = int(np.asarray(_pick_last(logits))[0])
             req.generated.append(nxt)
             req.first_token_at = time.perf_counter()
             req.pos = T
-            self.last_tokens = self.last_tokens.at[slot, 0].set(nxt)
+            self.last_tokens[slot, 0] = nxt
             self._events.append(TokenEvent(req.rid, nxt,
                                            len(req.generated) - 1, req.done))
             # a request whose budget is a single token finishes on its
@@ -365,6 +388,12 @@ class ContinuousBatcher:
         self.degradations.append({"iteration": self.iterations,
                                   "level": level, "reason": str(exc)})
 
+    def _tokens_dev(self, rows: slice = slice(None)):
+        """Upload ``last_tokens[rows]`` for one pass. The copy keeps the
+        upload from aliasing the host buffer, which the next commit writes
+        while the pass may still run."""
+        return jax.device_put(self.last_tokens[rows].copy())
+
     def _run_slot(self, slot: int, tokens, pos):
         """Runs a single-sequence chunk against the shared KV slot. The
         executor's caches are stacked (L, B, KV, S, hd) arrays, so slot
@@ -424,10 +453,10 @@ class ContinuousBatcher:
             len(active), queue_depth=self.ex.sched_queue_depth,
             slack_s=self.ex.sched_slack_s))
         logits, self.kv = self.ex._run_decode(
-            self.last_tokens, self.kv, jnp.asarray(pos_vec),
+            self._tokens_dev(), self.kv, jnp.asarray(pos_vec),
             jnp.asarray(mask), n_active=len(active))
         with TraceAnnotation("serving.sample"):
-            nxt = np.asarray(greedy_token(logits[:, -1]))
+            nxt = np.asarray(_pick_last(logits))
             for i in active:
                 self._advance_guard(i, int(nxt[i]))
 
@@ -470,7 +499,7 @@ class ContinuousBatcher:
             pos_vec[i] = r.pos
             mask[i] = True
             prev_tok[i] = self._seq_token(r, r.pos - 1)
-        last = np.asarray(self.last_tokens).reshape(-1)
+        last = self.last_tokens.reshape(-1).copy()
         drafts = self.spec.draft(prev_tok, last, pos_vec, mask, k,
                                  n_active=len(active))
         tokens = np.concatenate([last[:, None], drafts],
@@ -485,7 +514,7 @@ class ContinuousBatcher:
             jnp.asarray(tokens), self.kv, jnp.asarray(pos_vec),
             jnp.asarray(mask), n_active=len(active))
         with TraceAnnotation("serving.sample"):
-            targets = np.asarray(greedy_token(logits))  # (B, W)
+            targets = np.asarray(_pick_all(logits))  # (B, W)
             keep_pos = np.zeros((B,), np.int32)
             roll_mask = np.zeros((B,), bool)
             st = self.ex.stats
@@ -531,9 +560,9 @@ class ContinuousBatcher:
         seed's B=1 slice loop."""
         if self.ex.engine is None:
             for i in active:
-                logits = self._run_slot(i, self.last_tokens[i:i + 1],
+                logits = self._run_slot(i, self._tokens_dev(slice(i, i + 1)),
                                         self.slots[i].pos)
-                self._advance_guard(i, int(greedy_token(logits[0, -1])))
+                self._advance_guard(i, int(np.asarray(_pick_last(logits))[0]))
             return
         pos_vec = np.zeros((self.max_batch,), np.int32)
         for i in active:
@@ -546,9 +575,9 @@ class ContinuousBatcher:
                 1, queue_depth=self.ex.sched_queue_depth,
                 slack_s=self.ex.sched_slack_s))
             logits, self.kv = self.ex._run_decode(
-                self.last_tokens, self.kv, pos_vec, jnp.asarray(mask),
+                self._tokens_dev(), self.kv, pos_vec, jnp.asarray(mask),
                 n_active=1)
-            self._advance_guard(i, int(greedy_token(logits[i, -1])))
+            self._advance_guard(i, int(np.asarray(_pick_last(logits))[i]))
 
     def _advance_guard(self, slot: int, token: int):
         """Per-request isolation on the decode commit path (DESIGN.md §15):
@@ -571,7 +600,7 @@ class ContinuousBatcher:
         req = self.slots[slot]
         req.generated.append(token)
         req.pos += 1
-        self.last_tokens = self.last_tokens.at[slot, 0].set(token)
+        self.last_tokens[slot, 0] = token
         self._events.append(TokenEvent(req.rid, token,
                                        len(req.generated) - 1, req.done))
         if req.done:

@@ -141,3 +141,28 @@ def test_fused_decode_does_not_retrace(db):
     b.serve(staggered_requests(cfg, n=2, max_new=3))
     assert dict(b.ex.engine.trace_counts) == traces, \
         "fused decode re-traced across iterations"
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_decode_step_makes_no_implicit_upload(fused, db):
+    """The per-token bookkeeping stays on the host: once the admissions ran,
+    a decode-only step uploads the last tokens explicitly, once per pass,
+    and launches no per-slot device update, so it completes with implicit
+    host-to-device transfers disallowed (fused and per-slot paths)."""
+    cfg, params, sched = make("yi-9b", db, batch=4)
+    b = ContinuousBatcher(cfg, params, sched, max_batch=4, max_seq=64,
+                          fused=fused)
+    assert b.fused == fused
+    reqs = staggered_requests(cfg, n=4, max_new=6)
+    b.submit(reqs)
+    b.step()  # admits all four and decodes once, compiling every shape
+    assert not b.pending and all(s is not None for s in b.slots)
+    with jax.transfer_guard_host_to_device("disallow"):
+        for _ in range(2):
+            events = b.step()
+            assert len(events) == 4
+    assert isinstance(b.last_tokens, np.ndarray)
+    assert b.last_tokens.shape == (4, 1)
+    for i, r in enumerate(b.slots):
+        assert r is reqs[i] and len(r.generated) == 4
+        assert b.last_tokens[i, 0] == r.generated[-1]
