@@ -23,7 +23,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from chipbench import flops, model, traffic
+from chipbench import families, model, traffic
 from chipbench.loop import ClosedLoop
 from chipbench.peaks import Peaks
 
@@ -48,6 +48,12 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     limit: Optional[float]         # of CHECK; None until calibrated
+
+    @property
+    def family(self):
+        """The module of the configuration's family
+        (``chipbench/families/<family>.py``)."""
+        return families.load(self.raw["family"])
 
 
 def load_limit(name: str) -> Optional[float]:
@@ -92,8 +98,9 @@ def reader(name: str) -> Callable:
 @dataclass
 class Window:
     """What a metric reader reads: the window's steps and requests by the
-    host clock, the program's counters over the window, and with
-    ``--trace 1`` the trace's reduction."""
+    host clock, the program's counters over the window, the family's
+    counts (``family``, over ``shapes``), and with ``--trace 1`` the
+    trace's reduction."""
     start: float
     end: float
     setup_s: float
@@ -103,9 +110,10 @@ class Window:
     decode_pass_streamed: list
     peak_bytes: Optional[int]
     budget_bytes: int
-    shapes: flops.Shapes
+    shapes: object
     peaks: Optional[Peaks]
     trace: object = None
+    family: object = None
 
     @property
     def seconds(self) -> float:
@@ -245,9 +253,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from chipbench import reference
     meter = CompileMeter()
     meter.install()
-    raw, spec = cell.raw, cell.spec
-    cfg = model.model_config(raw)
-    params = model.make_weights(raw, seed)
+    raw, spec, fam = cell.raw, cell.spec, cell.family
+    cfg = fam.model_config(raw)
+    params = fam.make_weights(raw, seed)
     requests = traffic.generate(spec, cfg.vocab, seed)
     sess = open_session(cell, cfg, params, system)
     batcher = sess.batcher(max_batch=spec.clients)
@@ -288,7 +296,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                counters=delta(c0, c1),
                decode_pass_streamed=list(ex.stats.pass_streamed_bytes[n0:]),
                peak_bytes=peak, budget_bytes=sess.budget_bytes,
-               shapes=flops.shapes_of(cfg), peaks=peaks, trace=summary)
+               shapes=fam.shapes_of(cfg), peaks=peaks, trace=summary,
+               family=fam)
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:
@@ -312,13 +321,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     t_ref = time.perf_counter()
     if seqs:
         size = dict(length=spec.max_seq, rows=spec.output_max)
-        ref = reference.logits_at(raw, params, seqs, **size)
+        ref = fam.logits_at(raw, params, seqs, **size)
         gaps = np.concatenate([reference.gaps(r, s)
                                for r, (_, s) in zip(ref, seqs)])
         gap, widest = float(gaps.mean()), float(gaps.max())
         off = int((gaps > 0).sum())
         for kind in controls:
-            low = reference.logits_at(raw, params, seqs, low=kind, **size)
+            low = fam.logits_at(raw, params, seqs, low=kind, **size)
             gaps = np.concatenate([reference.gaps(r, c.argmax(-1))
                                    for r, c in zip(ref, low)])
             control_gaps[kind] = {CHECK: float(gaps.mean()),

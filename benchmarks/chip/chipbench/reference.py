@@ -1,19 +1,14 @@
-"""Plain float32 reference of a dense SwiGLU decoder (Qwen2 / Llama
-layout), written from the published architecture and independent of the
-program: pre-norm RMSNorm, grouped-query attention with rotary positions
-(the half-split rotation), optional q/k/v biases, a SwiGLU MLP, a final
-RMSNorm and a tied or separate output head. Every matmul runs at
-``Precision.HIGHEST``, so the chip does not round it to bfloat16.
-
-It runs layer by layer over a block of sequences, so only one layer's
-weights and the block's residuals are on the device at a time.
+"""Plain float32 arithmetic that the families' references are written
+with, independent of the program: matmuls at ``Precision.HIGHEST`` (so
+the chip does not round them to bfloat16), RMSNorm, rotary positions,
+causal grouped-query attention, the low-precision roundings of the
+controls, and the gap of a picked token below the reference's best.
+Each family's ``logits_at`` (``families/<family>.py``) builds on them.
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Optional
-
 import numpy as np
+
 
 def _mm(a, b):
     import jax.numpy as jnp
@@ -84,83 +79,6 @@ def _low_mm(kind):
         return _mm
     q = LOW[kind]
     return lambda a, w: _mm(q(a, -1), q(w, 0))
-
-
-def _layer(w, x, *, heads, kv_heads, head_dim, theta, eps, low):
-    """One decoder layer over a block of sequences x: (N, S, d)."""
-    import jax
-    import jax.numpy as jnp
-    mm = _low_mm(low)
-    w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-    a = w["attn"]
-    S = x.shape[1]
-    pos = jnp.arange(S)
-
-    def one(xs):
-        h = _rmsnorm(xs, w["ln1"], eps)
-        q, k, v = mm(h, a["wq"]), mm(h, a["wk"]), mm(h, a["wv"])
-        if "bq" in a:
-            q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-        q = _rope(q.reshape(S, heads, head_dim), pos, theta)
-        k = _rope(k.reshape(S, kv_heads, head_dim), pos, theta)
-        v = v.reshape(S, kv_heads, head_dim)
-        xs = xs + mm(_attention(q, k, v), a["wo"])
-        h = _rmsnorm(xs, w["ln2"], eps)
-        f = w["ffn"]
-        g = mm(h, f["w_gate"])
-        return xs + mm(g * jax.nn.sigmoid(g) * mm(h, f["w_up"]),
-                       f["w_down"])
-
-    return jax.lax.map(one, x)
-
-
-def logits_at(raw: dict, params: dict, seqs, low: Optional[str] = None,
-              length: int = 0, rows: int = 0) -> list:
-    """The logits that chose each served token: for each ``(prompt,
-    served)`` pair, a ``(len(served), vocab)`` float32 array whose row
-    ``i`` is the output at position ``len(prompt) - 1 + i``, teacher-forced
-    on the prompt and the served tokens. ``params`` are the host weights
-    the program was given. ``low`` (``"int8"`` or ``"fp8"``) computes every
-    weight matmul with operands in that precision instead (the control). Sequences run one at a time,
-    padded to ``length`` positions and ``rows`` served tokens (at least
-    their longest), so a cell compiles the same programs every run."""
-    import jax
-    import jax.numpy as jnp
-    S = max([length] + [len(p) + len(s) - 1 for p, s in seqs])
-    R = max([rows] + [len(s) for _, s in seqs])
-    embed = jnp.asarray(params["embed"])
-    xs = []
-    for p, s in seqs:
-        row = np.zeros((1, S), np.int32)
-        n = len(p) + len(s) - 1
-        row[0, :n] = np.concatenate([np.asarray(p, np.int32),
-                                     np.asarray(s[:-1], np.int32)])
-        xs.append(jnp.take(embed, jnp.asarray(row), axis=0)
-                  .astype(jnp.float32))
-    layer = jax.jit(partial(
-        _layer, heads=raw["num_attention_heads"],
-        kv_heads=raw["num_key_value_heads"], head_dim=raw["head_dim"],
-        theta=float(raw["rope_theta"]), eps=float(raw["rms_norm_eps"]),
-        low=low))
-    for i in range(raw["num_hidden_layers"]):
-        w = jax.tree.map(lambda a: jnp.asarray(a[i]), params["layers"])
-        xs = [layer(w, x) for x in xs]
-        del w
-    head = embed.T if raw["tie_word_embeddings"] else \
-        jnp.asarray(params["unembed"])
-    final = jnp.asarray(params["final_norm"])
-    eps = float(raw["rms_norm_eps"])
-
-    @jax.jit
-    def out(x, start, final, head):
-        xr = jax.lax.dynamic_slice_in_dim(
-            jnp.pad(x[0], ((0, R), (0, 0))), start, R)
-        return _low_mm(low)(
-            _rmsnorm(xr, final.astype(jnp.float32), eps),
-            head.astype(jnp.float32))
-
-    return [np.asarray(out(x, len(p) - 1, final, head))[:len(s)]
-            for x, (p, s) in zip(xs, seqs)]
 
 
 def gaps(ref_logits, picked) -> np.ndarray:

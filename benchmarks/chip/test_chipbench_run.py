@@ -23,6 +23,7 @@ for p in (CHIP, ROOT / "src"):
         sys.path.insert(0, str(p))
 
 from chipbench import harness, model, reference, traffic  # noqa: E402
+from chipbench.families import dense  # noqa: E402
 
 TESTDATA = CHIP / "testdata"
 CELL = "yi-9b-l8.tight.batch8"
@@ -131,8 +132,8 @@ def test_reference_matches_session_logits():
     from repro.core import InferenceSetting
     from repro.core.system import TPU_V5E
     raw = model.load_config(TESTDATA / "smoke-dense.json")
-    cfg = model.model_config(raw)
-    params = model.make_weights(raw, 2**35 + 1)
+    cfg = dense.model_config(raw)
+    params = dense.make_weights(raw, 2**35 + 1)
     sess = Session.open(cfg, TPU_V5E, 10**9, InferenceSetting(
         batch=1, context=64), max_seq=64, params=params)
     ex = sess.executor
@@ -153,7 +154,7 @@ def test_reference_matches_session_logits():
     # teacher-forced on those served tokens
     first = int(np.argmax(got[0]))
     tokens = [first] + [int(t) for t in served[:-1]]
-    ref = reference.logits_at(raw, params, [(prompt, tokens)])[0]
+    ref = dense.logits_at(raw, params, [(prompt, tokens)])[0]
     assert ref.shape == got.shape
     scale = float(np.abs(ref).max())
     assert float(np.abs(got - ref).max()) <= 0.02 * scale
@@ -162,9 +163,9 @@ def test_reference_matches_session_logits():
 
 def test_reference_gap_of_its_own_argmax_is_zero():
     raw = model.load_config(TESTDATA / "smoke-dense.json")
-    params = model.make_weights(raw, 9)
+    params = dense.make_weights(raw, 9)
     prompt = np.arange(11) % raw["vocab_size"]
-    ref = reference.logits_at(raw, params, [(prompt, [1, 2, 3, 4])])[0]
+    ref = dense.logits_at(raw, params, [(prompt, [1, 2, 3, 4])])[0]
     assert np.all(reference.gaps(ref, ref.argmax(-1)) == 0)
     assert np.all(reference.gaps(ref, ref.argmin(-1)) > 0)
 
@@ -200,12 +201,12 @@ def test_control_fails_the_limit(seed):
     below the reference's best, on the mean, than the limit allows, while
     the reference's own picks read 0."""
     raw = model.load_config(TESTDATA / "test-dense.json")
-    params = model.make_weights(raw, seed)
+    params = dense.make_weights(raw, seed)
     rng = np.random.default_rng(seed)
     seqs = [(rng.integers(0, raw["vocab_size"], 40),
              list(rng.integers(0, raw["vocab_size"], 24))) for _ in range(6)]
-    ref = reference.logits_at(raw, params, seqs)
-    low = reference.logits_at(raw, params, seqs, low="int8")
+    ref = dense.logits_at(raw, params, seqs)
+    low = dense.logits_at(raw, params, seqs, low="int8")
     control = np.concatenate([reference.gaps(r, c.argmax(-1))
                               for r, c in zip(ref, low)])
     own = np.concatenate([reference.gaps(r, r.argmax(-1)) for r in ref])

@@ -18,6 +18,7 @@ for p in (CHIP, ROOT / "src"):
         sys.path.insert(0, str(p))
 
 from chipbench import flops, harness, traffic  # noqa: E402
+from chipbench.families import dense  # noqa: E402
 from chipbench.loop import Sent, StepRecord  # noqa: E402
 from chipbench.peaks import PEAKS, peaks_for  # noqa: E402
 
@@ -162,21 +163,21 @@ def test_every_metric_has_a_reader_and_every_cell_its_files():
 
 
 # ---------------------------------------------------------------- flops
-QWEN = flops.Shapes(d=896, heads=14, kv_heads=2, head_dim=64, d_ff=4864,
+QWEN = dense.Shapes(d=896, heads=14, kv_heads=2, head_dim=64, d_ff=4864,
                     vocab=151936, layers=24, qkv_bias=True, tied=True)
-YI = flops.Shapes(d=4096, heads=32, kv_heads=4, head_dim=128, d_ff=11008,
+YI = dense.Shapes(d=4096, heads=32, kv_heads=4, head_dim=128, d_ff=11008,
                   vocab=64000, layers=8, qkv_bias=False, tied=False)
 
 
 def test_ffn_counts_by_hand():
     # qwen2-0.5b: 3 * 896 * 4864 weights of 2 bytes, plus the norm
-    assert flops.ffn_bytes(QWEN, 1) == 2 * (3 * 896 * 4864 + 896) \
+    assert dense.ffn_bytes(QWEN, 1) == 2 * (3 * 896 * 4864 + 896) \
         + 2 * 896 * 2
-    assert flops.ffn_flops(QWEN, 8) == 2 * 8 * 3 * 896 * 4864
+    assert dense.ffn_flops(QWEN, 8) == 2 * 8 * 3 * 896 * 4864
     # yi-9b: 135266304 weights per FFN
-    assert flops.ffn_bytes(YI, 512) == 2 * (135266304 + 4096) \
+    assert dense.ffn_bytes(YI, 512) == 2 * (135266304 + 4096) \
         + 2 * 512 * 4096 * 2
-    assert flops.ffn_flops(YI, 512) == pytest.approx(138.5e9, rel=1e-3)
+    assert dense.ffn_flops(YI, 512) == pytest.approx(138.5e9, rel=1e-3)
 
 
 def test_attention_decode_counts_by_hand():
@@ -184,9 +185,9 @@ def test_attention_decode_counts_by_hand():
     params = 2 * 4096 * 4096 + 2 * 4096 * 512 + 4096
     assert YI.attn_params == params
     ctx = [100, 1]
-    assert flops.attn_decode_bytes(YI, ctx) == (
+    assert dense.attn_decode_bytes(YI, ctx) == (
         2 * params + 2 * 512 * 101 * 2 + 2 * (2 * 512 + 2 * 4096) * 2)
-    assert flops.attn_decode_flops(YI, ctx) == (
+    assert dense.attn_decode_flops(YI, ctx) == (
         2 * 2 * (4096 * (4096 + 1024) + 4096 * 4096) + 4 * 4096 * 101)
     # qwen2-0.5b carries q, k and v biases
     assert QWEN.attn_params == (2 * 896 * 896 + 2 * 896 * 128 + 896
@@ -196,11 +197,11 @@ def test_attention_decode_counts_by_hand():
 def test_model_counts_by_hand():
     per_layer = (2 * (896 * (896 + 256) + 896 * 896) + 4 * 896 * 10
                  + 2 * 3 * 896 * 4864)
-    assert flops.token_flops(QWEN, 10) == 24 * per_layer + 2 * 896 * 151936
+    assert dense.token_flops(QWEN, 10) == 24 * per_layer + 2 * 896 * 151936
     # a prompt of one token is one token at context 1
-    assert flops.prompt_flops(YI, 1) == flops.token_flops(YI, 1)
-    assert flops.prompt_flops(YI, 3) == pytest.approx(
-        sum(flops.token_flops(YI, c) for c in (1, 2, 3))
+    assert dense.prompt_flops(YI, 1) == dense.token_flops(YI, 1)
+    assert dense.prompt_flops(YI, 3) == pytest.approx(
+        sum(dense.token_flops(YI, c) for c in (1, 2, 3))
         - 2 * 2 * 4096 * 64000)
 
 
@@ -268,18 +269,19 @@ def test_step_tokens_reads_prefills_and_decode_contexts_from_stamps():
 def test_kernel_roofline_counts_each_step_once_per_layer(name, module):
     from chipbench.trace import TraceSummary
     w = _two_steps()
-    w.shapes = flops.shapes_of(types.SimpleNamespace(
+    w.family = dense
+    w.shapes = dense.shapes_of(types.SimpleNamespace(
         d_model=64, n_heads=4, n_kv_heads=2, resolved_head_dim=16, d_ff=128,
         vocab=256, n_layers=3, qkv_bias=False, tie_embeddings=True))
     w.peaks = peaks_for("TPU v5 lite")
     s, p = w.shapes, w.peaks
     if module == "_ffn_step":
         need = sum(3 * flops.roofline_s(
-            flops.ffn_flops(s, n), flops.ffn_bytes(s, n), p.bf16_flops,
+            dense.ffn_flops(s, n), dense.ffn_bytes(s, n), p.bf16_flops,
             p.hbm_bytes) for n in (138, 3))
     else:
         need = sum(3 * flops.roofline_s(
-            flops.attn_decode_flops(s, c), flops.attn_decode_bytes(s, c),
+            dense.attn_decode_flops(s, c), dense.attn_decode_bytes(s, c),
             p.bf16_flops, p.hbm_bytes) for c in ([25], [131, 8, 26]))
     w.trace = TraceSummary(window_s=1.0, busy_s=0.5, chips=1,
                            module_s={module: 2 * need})
